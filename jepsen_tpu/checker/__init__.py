@@ -7,7 +7,7 @@ is the knossos replacement — the framework's north star.
 Re-exports resolve lazily (PEP 562): importing a host-only submodule
 (wgl_oracle, wgl_native, events, models) must not drag in the jax-backed
 engines — spawned bounded-pmap oracle workers and jax-free CLI paths
-depend on the import chain staying clean of accelerator plugins.
+depend on the import chain staying clean of JAX.
 """
 
 _EXPORTS = {

@@ -31,8 +31,8 @@ from typing import Any, Callable, Dict, Optional, Tuple
 
 # jax is imported lazily inside the *_jax step functions (they only run
 # under jit tracing): the CPU oracle's import chain — including spawned
-# bounded-pmap workers, which must never touch an ambient TPU plugin —
-# stays jax-free.
+# bounded-pmap workers, which must never touch the chip — stays
+# jax-free.
 
 NIL = -1
 

@@ -166,7 +166,8 @@ def _apply_mesh_args(args) -> None:
     pod flags (or the JEPSEN_TPU_POD_* env they override) join the
     pod FIRST (jax.distributed must initialize before the first device
     query), then the mesh policy pins what sharded.resolve_mesh's
-    ambient default_mesh may span."""
+    ambient default_mesh may span. Then the stderr banner names the
+    platform, device_kind and count the run is on."""
     from jepsen_tpu.checker import sharded
     from jepsen_tpu.pod import topology
 
@@ -183,6 +184,11 @@ def _apply_mesh_args(args) -> None:
         devices=getattr(args, "devices", None),
         backend=getattr(args, "backend", None),
     )
+    from jepsen_tpu.obs.snapshot import device_info
+
+    d = device_info()
+    print(f"device: platform={d['platform']} kind={d['kind']!r} "
+          f"count={d['count']} jax={d['jax']}", file=sys.stderr)
 
 
 def cmd_test(args) -> int:

@@ -42,6 +42,8 @@ def engine_snapshot() -> dict:
     - ``perf``:      the self-tuning perf plane's disclosure — the
       resolved knob ``config_hash``, whether a persisted tuned
       profile is active, and where it was loaded from
+    - ``device``:    what JAX runs on (``device_info``), so a CPU run
+      is never read as a chip run
     """
     from jepsen_tpu.checker import chaos, checkpoint, dispatch, sharded
     from jepsen_tpu.checker import streaming, txn_graph
@@ -58,6 +60,21 @@ def engine_snapshot() -> dict:
         "txn_graph": txn_graph.txn_graph_stats(),
         "trace": _trace.trace_stats(),
         "perf": perf_knobs.perf_snapshot(),
+        "device": device_info(),
+    }
+
+
+def device_info() -> dict:
+    """The platform, ``device_kind`` and device count JAX runs on, and
+    the JAX version: the device every number of this process names."""
+    import jax
+
+    devs = jax.devices()
+    return {
+        "platform": devs[0].platform,
+        "kind": devs[0].device_kind,
+        "count": len(devs),
+        "jax": jax.__version__,
     }
 
 

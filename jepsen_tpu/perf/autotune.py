@@ -1,13 +1,12 @@
 """Profile store + min-of-N verdict-parity-checked knob sweep.
 
-The profile lives next to the XLA compile cache (the
-``JAX_COMPILATION_CACHE_DIR`` convention ``pod/launcher.py`` has
-always used), one JSON per ``(backend, n_devices, jax_version)`` key:
-the same sweep that is right for a v5e pod is wrong for the CPU
-interpret tier, and a jax upgrade invalidates both (compile behavior
-shifts under the knobs). Loading is paranoid and silent: a corrupt,
-foreign-keyed, or stale-jax profile degrades to registry defaults —
-the perf plane may never change a verdict or break a construction.
+The profile lives under ``~/.cache/jepsen_tpu/perf_profiles``, one
+JSON per ``(backend, n_devices, jax_version)`` key: the same sweep that
+is right for a v5e pod is wrong for the CPU interpret tier, and a jax
+upgrade invalidates both (compile behavior shifts under the knobs).
+Loading is paranoid and silent: a corrupt, foreign-keyed, or stale-jax
+profile degrades to registry defaults — the perf plane may never
+change a verdict or break a construction.
 
 The sweep is coordinate descent over the registry in declaration
 order: each knob's rungs are timed min-of-N on a reduced-scale probe
@@ -53,30 +52,40 @@ FAKE_CLOCK_ENV = "JEPSEN_TPU_TUNE_FAKE_CLOCK"
 # -- the cache-root convention ----------------------------------------------
 
 
+#: the checkout root: the compile cache lives inside it, at a fixed path
+REPO_ROOT = os.path.dirname(
+    os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+)
+
+
 def cache_root() -> str:
-    """``~/.cache/jepsen_tpu`` — the one root the compile cache and
-    the perf profiles share (pod/launcher.py's convention)."""
+    """``~/.cache/jepsen_tpu`` — where the perf profiles live."""
     return os.path.join(
         os.path.expanduser("~"), ".cache", "jepsen_tpu"
     )
 
 
 def compile_cache_dir() -> str:
-    return os.path.join(cache_root(), "jax_cache")
+    """THE compile-cache path: ``JAX_COMPILATION_CACHE_DIR`` when set,
+    else ``<repo>/.jax_cache`` (git-ignored). The path is part of the
+    cache key, so it is fixed: never a temp name, a pid or a time."""
+    return os.environ.get("JAX_COMPILATION_CACHE_DIR") or os.path.join(
+        REPO_ROOT, ".jax_cache"
+    )
 
 
 def enable_persistent_compile_cache() -> str:
-    """Point jax at the persistent on-disk compile cache (idempotent;
-    an explicit JAX_COMPILATION_CACHE_DIR in the environment wins).
-    pod/launcher.py has always done this for spawned members — calling
-    it from the single-process entry points (cli analyze/daemon,
-    bench) gives every run the same warm-start."""
-    d = os.environ.setdefault("JAX_COMPILATION_CACHE_DIR",
-                              compile_cache_dir())
+    """Point jax at the persistent on-disk compile cache (idempotent).
+    Applied through jax.config so it holds even when jax was imported
+    first; pod/launcher.py hands the same path to spawned members."""
+    import jax
+
+    d = compile_cache_dir()
     try:
         os.makedirs(d, exist_ok=True)
     except OSError:
-        pass  # unwritable home: jax will just skip the cache
+        pass  # unwritable checkout: jax will just skip the cache
+    jax.config.update("jax_compilation_cache_dir", d)
     return d
 
 
@@ -242,9 +251,9 @@ def load_active_profile() -> Optional[str]:
 
 
 def _interpret() -> bool:
-    import jax
+    from jepsen_tpu.checker.linearizable import interpret_off_chip
 
-    return jax.default_backend() != "tpu"
+    return interpret_off_chip("tune")
 
 
 def _probe_linear() -> Callable[[], dict]:

@@ -91,7 +91,7 @@ def pod_env(
     # call perf.autotune.enable_persistent_compile_cache, the same
     # path): tier-1 launches several short-lived pods, and without
     # this every member re-pays the full XLA compile of the same
-    # shard_map programs. The perf-profile store lives beside it.
+    # shard_map programs.
     from jepsen_tpu.perf.autotune import compile_cache_dir
 
     env.setdefault("JAX_COMPILATION_CACHE_DIR", compile_cache_dir())

@@ -3,7 +3,6 @@ Not pytest-collected."""
 import os, sys, time
 os.environ["JAX_PLATFORMS"] = "cpu"
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
-import jax; jax.config.update("jax_platforms", "cpu")
 import random
 
 from jepsen_tpu.checker.events import history_to_events

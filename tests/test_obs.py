@@ -6,6 +6,7 @@ snapshot, and the analyze --trace / trace-summary CLI surfaces."""
 
 import json
 import re
+import sys
 import threading
 
 import pytest
@@ -257,8 +258,13 @@ def test_engine_snapshot_is_the_one_reader():
     snap = engine_snapshot()
     assert set(snap) == {
         "dispatch", "launch", "mesh", "resilience", "checkpoint",
-        "streaming", "txn_graph", "trace", "perf",
+        "streaming", "txn_graph", "trace", "perf", "device",
     }
+    # every surface names the device it ran on (CPU under tier-1)
+    assert snap["device"]["platform"] == "cpu"
+    assert snap["device"]["count"] == (
+        snap["mesh"]["topology"]["global_devices"]
+    )
     # sections carry their planes' own snapshot shapes
     assert "launches" in snap["launch"]
     assert "enabled" in snap["trace"]
@@ -327,6 +333,11 @@ def test_cli_analyze_trace_and_summary(tmp_path, capsys, monkeypatch):
     # Pallas interpret mode: the seam that takes the device branch
     # (and therefore pays counted launches/syncs) on a CPU-only host
     monkeypatch.setenv("JEPSEN_TPU_INTERPRET", "1")
+    # no native racer: a racer win leaves the launch uncollected (zero
+    # host syncs), which made this parity pin depend on compile timing
+    monkeypatch.setattr(
+        sys.modules["jepsen_tpu.checker.linearizable"], "RACE_MAX_OPS", 0
+    )
     store_root = str(tmp_path / "store")
     assert main([
         "test", "--workload", "register", "--ops", "40",
