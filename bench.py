@@ -138,9 +138,6 @@ def trend_row_from_record(record: dict, *, ts=None, smoke=None) -> dict:
             "double_buffer_occupancy"
         ),
         "trace_overhead_pct": record.get("trace_overhead_pct"),
-        # the sampled-recorder config + its measured overhead (the
-        # production tracing story: per-kind mask, 1-in-N sampling)
-        "trace_sampled": record.get("trace_sampled"),
         # fleet rows stamp their member count; solo rows omit the key
         # (trend_fleet defaults to 1), so a 2-member aggregate is
         # never gated against a solo trajectory.
@@ -195,21 +192,14 @@ def append_trend_row(row: dict, path: str = None) -> str:
     return path
 
 
-def measure_trace_overhead_pct(
-    n: int = 20, sample_n=None, kinds=None,
-) -> float:
+def measure_trace_overhead_pct(n: int = 20) -> float:
     """Tracing-ON cost relative to a sync-floor launch: wall of n
     probe launches with the flight recorder off vs on, the ON pass
     carrying the per-launch emission density wgl_bitset actually pays
     (one span + two launch_stat instants per launch). The published
     number is what turning the recorder on adds to real launch-bound
     work — near zero, because emission is appended to a thread-local
-    list while the launch pays a device round trip.
-
-    sample_n / kinds re-measure under the production sampled config
-    (obs.trace enable(kinds=..., sample_n=...)): the masked/sampled-
-    out emissions skip the clock and the ring, which is what pulls the
-    launch-loop overhead under the 10% acceptance bound."""
+    list while the launch pays a device round trip."""
     import jax
     import jax.numpy as jnp
     import numpy as _np
@@ -247,11 +237,10 @@ def measure_trace_overhead_pct(
         for _ in range(5):
             obs_trace.disable()
             off = min(off, _pass(False))
-            obs_trace.enable(kinds=kinds, sample_n=sample_n)
+            obs_trace.enable()
             on = min(on, _pass(True))
     finally:
         obs_trace.reset()
-        obs_trace.enable()  # restore the full-fidelity config
         if not was_on:
             obs_trace.disable()
     if off <= 0:
@@ -2409,24 +2398,6 @@ def main() -> None:
         "launch (recorder ON vs OFF, full fidelity)",
         file=sys.stderr,
     )
-    # The production sampled config: launch-kind spans only, 1-in-16.
-    # This is the number the ≤10% acceptance bound and the trend row
-    # pin — full-fidelity stays published alongside for contrast.
-    _sampled_cfg = {"kinds": ["launch"], "sample_n": 16}
-    trace_sampled_pct = round(
-        measure_trace_overhead_pct(
-            kinds=_sampled_cfg["kinds"],
-            sample_n=_sampled_cfg["sample_n"],
-        ),
-        2,
-    )
-    trace_sampled = dict(_sampled_cfg, overhead_pct=trace_sampled_pct)
-    print(
-        f"trace_overhead(sampled kinds={_sampled_cfg['kinds']} "
-        f"1/{_sampled_cfg['sample_n']}): {trace_sampled_pct:.2f}% "
-        "per sync-floor launch",
-        file=sys.stderr,
-    )
     ns = next(c for c in configs if c["name"] == "northstar-100k")
     record = {
                 "metric": "ops_verified_per_sec",
@@ -2436,7 +2407,6 @@ def main() -> None:
                 "vs_baseline": round(geomean, 3),
                 "vs_python_oracle": round(py_geomean, 3),
                 "trace_overhead_pct": trace_overhead_pct,
-                "trace_sampled": trace_sampled,
                 "baseline": "strongest measured CPU per config "
                             "(see stderr + BENCH_NOTES.md)",
                 "host_cores": os.cpu_count(),

@@ -42,6 +42,7 @@ from jepsen_tpu.checker.events import (
 )
 from jepsen_tpu.checker.wgl_oracle import check_events_fast as oracle_check_fast
 from jepsen_tpu.checker.wgl_jax import check_steps_jax
+from jepsen_tpu.obs import trace as obs_trace
 
 #: K escalation ladder: frontier capacities tried in order. Starts at
 #: 128: measured closure-width distributions on register workloads put
@@ -216,10 +217,23 @@ def _harvest_failure(events: EventStream, out: dict, model) -> None:
         return
     from jepsen_tpu.checker.wgl_oracle import check_events
 
-    _, py_stats = check_events(events, model=model, return_stats=True)
-    failure = oracle_failure_report(events, py_stats, model)
+    with obs_trace.span("verdict.harvest", kind="verdict"):
+        _, py_stats = check_events(events, model=model, return_stats=True)
+        failure = oracle_failure_report(events, py_stats, model)
     if failure is not None:
         out["failure"] = failure
+
+
+def _decode_death(frontier, bsteps, died: int, model, events):
+    """The failure report of a bitset-kernel death, decoded from the
+    dying frontier the kernel already returned."""
+    from jepsen_tpu.checker.wgl_bitset import decode_frontier
+
+    with obs_trace.span("verdict.harvest", kind="verdict"):
+        return decode_frontier(
+            frontier, bsteps, died, model,
+            decode_value=_decode_value(events),
+        )
 
 
 def _oracle_decide(events: EventStream, model):
@@ -266,6 +280,9 @@ class _NativeRacer:
         self.result: Optional[tuple] = None
         self.error: Optional[BaseException] = None
         ev, mdl = events, model
+        # the caller's span (the key's check): the racer's span hangs
+        # under it across the thread boundary
+        parent = obs_trace.current()
 
         def run():
             try:
@@ -273,9 +290,11 @@ class _NativeRacer:
                     check_events_native,
                 )
 
-                self.result = check_events_native(
-                    ev, model=mdl, return_stats=True
-                )
+                with obs_trace.span("racer.native", kind="racer",
+                                    parent=parent):
+                    self.result = check_events_native(
+                        ev, model=mdl, return_stats=True
+                    )
             except BaseException as e:  # noqa: BLE001 - report later
                 self.error = e
 
@@ -355,7 +374,8 @@ def _native_win_verdict(events, racer, model, escalations=0):
         # The native oracle carries no death-config material;
         # failure analysis is rare and worth a Python re-run
         # (the reference budgets hours for report writing).
-        _, py_stats, failure = _oracle_decide(events, model)
+        with obs_trace.span("verdict.harvest", kind="verdict"):
+            _, py_stats, failure = _oracle_decide(events, model)
         if failure is not None:
             out["failure"] = failure
     return out
@@ -370,15 +390,13 @@ def _race_decide(events, bsteps, handle, racer, model):
     by the RACE_MAX_OPS gate)."""
     import time as _time
 
-    while True:
-        if _tpu_handle_ready(handle):
-            return None
-        if racer.done():
-            out = _native_win_verdict(events, racer, model)
-            if out is None:
-                return None  # oracle crashed/declined: TPU decides
-            return out
-        _time.sleep(0.001)
+    with obs_trace.span("device_wait", kind="sync"):
+        while not _tpu_handle_ready(handle) and not racer.done():
+            _time.sleep(0.001)
+    if _tpu_handle_ready(handle):
+        return None
+    # native win, or None when the oracle crashed/declined: TPU decides
+    return _native_win_verdict(events, racer, model)
 
 
 def _race_crosscheck(racer, tpu_alive: bool) -> None:
@@ -387,7 +405,8 @@ def _race_crosscheck(racer, tpu_alive: bool) -> None:
     A mismatch means an engine bug; it is logged loudly and counted
     (the differential soaks treat any mismatch as a failure)."""
     _bump_race("tpu_wins")
-    racer.join(0.05)
+    with obs_trace.span("racer.wait", kind="racer"):
+        racer.join(0.05)
     if not racer.done() or racer.error or racer.result is None:
         return
     _bump_race("crosschecked")
@@ -438,28 +457,29 @@ def check_events_bucketed(
     """
     from jepsen_tpu.checker.models import model as get_model
 
-    W = _bucket_window(max(events.window, 1))
-    m = get_model(model)
-
     # Exact bitset kernel first: for windows <= 16 and small state
     # spaces it holds the ENTIRE config space, so its verdict is always
     # definite — no escalation ladder, no oracle fallback (wgl_bitset
     # module docstring). taint is impossible by construction; if it ever
     # fires, fall through to the capacity-ladder paths below.
     racer = None  # one native racer serves bitset AND ladder tiers
-    plan = (
-        _bitset_plan(events, m)
-        if (_on_tpu() or interpret)
-        else None
-    )
+    with obs_trace.span("prep.steps", kind="prep"):
+        W = _bucket_window(max(events.window, 1))
+        m = get_model(model)
+        plan = (
+            _bitset_plan(events, m)
+            if (_on_tpu() or interpret)
+            else None
+        )
+        if plan is not None:
+            bW, S = plan
+            bsteps = events_to_steps(events, W=bW)  # memoized per stream
     if plan is not None:
         from jepsen_tpu.checker.wgl_bitset import (
             collect_steps_bitset_segmented,
             launch_steps_bitset_segmented,
         )
 
-        bW, S = plan
-        bsteps = events_to_steps(events, W=bW)  # memoized per stream
         if checkpoint is not None:
             from jepsen_tpu.checker.wgl_bitset import (
                 check_steps_bitset_segmented,
@@ -492,21 +512,17 @@ def check_events_bucketed(
                     out["failed_op_index"] = died
                     fr = getattr(bsteps, "_death_frontier", None)
                     if fr is not None:
-                        from jepsen_tpu.checker.wgl_bitset import (
-                            decode_frontier,
-                        )
-
-                        out["failure"] = decode_frontier(
-                            fr, bsteps, died, model,
-                            decode_value=_decode_value(events),
+                        out["failure"] = _decode_death(
+                            fr, bsteps, died, model, events
                         )
                 return out
         # Segment-aware: the prefix before crashes widen the window
         # runs on the narrow (16x cheaper) kernel; padding/bucketing
         # happens per segment inside.
-        handle = launch_steps_bitset_segmented(
-            bsteps, model=model, S=S, interpret=interpret
-        )
+        with obs_trace.span("launch", kind="launch"):
+            handle = launch_steps_bitset_segmented(
+                bsteps, model=model, S=S, interpret=interpret
+            )
         if race is None:
             race = _race_eligible(events, m)
         if race:
@@ -541,13 +557,8 @@ def check_events_bucketed(
                 out["failed_op_index"] = died
                 fr = getattr(bsteps, "_death_frontier", None)
                 if fr is not None:
-                    from jepsen_tpu.checker.wgl_bitset import (
-                        decode_frontier,
-                    )
-
-                    out["failure"] = decode_frontier(
-                        fr, bsteps, died, model,
-                        decode_value=_decode_value(events),
+                    out["failure"] = _decode_death(
+                        fr, bsteps, died, model, events
                     )
             return out
     if (
@@ -1031,12 +1042,17 @@ class LinearizableChecker:
         starting over (the `analyze --resume` engine). Ignored by
         tiers that don't segment (K-ladder, oracle, queue-by-value).
         """
+        with obs_trace.span("check", kind="check"):
+            return self._check(test, history, opts, checkpoint)
+
+    def _check(self, test, history, opts, checkpoint) -> dict:
         from jepsen_tpu.history.history import History
 
-        if not isinstance(history, History):
-            history = History(history)
         t0 = time.perf_counter()
-        history, hreport = self._sentry(history)
+        with obs_trace.span("prep.sentry", kind="prep"):
+            if not isinstance(history, History):
+                history = History(history)
+            history, hreport = self._sentry(history)
         if self.model == "unordered-queue" and self.use_tpu:
             # Queue histories decompose by value (locality — see
             # split_queue_history_by_value): one batched kernel pass
@@ -1053,9 +1069,10 @@ class LinearizableChecker:
                 self._render_failure(test, out, opts)
                 return out
         try:
-            events = history_to_events(
-                history, model=self.model, init_value=self.init_value
-            )
+            with obs_trace.span("prep.encode", kind="prep"):
+                events = history_to_events(
+                    history, model=self.model, init_value=self.init_value
+                )
         except WindowOverflow:
             # Too concurrent for int32 masks: unbounded oracle decides
             # (and flows into the shared tail below — overflow runs get

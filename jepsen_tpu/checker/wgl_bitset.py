@@ -956,12 +956,13 @@ def collect_steps_bitset_segmented(
             # faults retry; exhaustion raises PlaneFault upward).
             from jepsen_tpu.checker import chaos
 
-            outs2, frs2, _ = chaos.resilient_call(
-                lambda: _run_chain(
-                    args, fr0, seg_ws, name, S, interpret, True
-                ),
-                site="launch",
-            )
+            with obs_trace.span("launch", kind="launch", exact=True):
+                outs2, frs2, _ = chaos.resilient_call(
+                    lambda: _run_chain(
+                        args, fr0, seg_ws, name, S, interpret, True
+                    ),
+                    site="launch",
+                )
             # planelint: disable=JT101 reason=the exact escalation re-run syncs ONCE (batched tuple fetch); the enclosing loop always exits via return after it
             for o2, f2 in zip(_host_get(tuple(outs2)), frs2):
                 alive2, t2, died2 = _out_to_verdicts(np.asarray(o2))[0]

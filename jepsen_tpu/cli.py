@@ -276,8 +276,9 @@ def cmd_analyze(args) -> int:
     trace). Inside a pod every member persists its ring into the
     shared trace dir and process 0 merges ONE clock-aligned trace;
     single-process runs export directly. --xla-trace DIR additionally
-    wraps the run in a jax.profiler capture (no-op where the profiler
-    is unavailable) so obs spans and the XLA timeline share a run.
+    wraps the run in a jax.profiler capture with the recorder on, so
+    the obs spans sit in the XLA profile's host plane, on the device
+    timeline's clock; a profiler that cannot start fails the command.
     Feed the file to ui.perfetto.dev or `jepsen_tpu trace-summary`."""
     trace_path = getattr(args, "trace", None)
     xla_dir = getattr(args, "xla_trace", None)
@@ -1087,8 +1088,9 @@ def build_parser() -> argparse.ArgumentParser:
                         "all members into one aligned trace)")
     a.add_argument("--xla-trace", default=None, metavar="DIR",
                    help="also capture a jax.profiler XLA trace into "
-                        "DIR (no-op where the profiler is "
-                        "unavailable, e.g. plain CPU meshes)")
+                        "DIR, with the flight recorder's spans on its "
+                        "host plane (fails if the profiler cannot "
+                        "start)")
     a.add_argument("--profile", default=None, metavar="PATH",
                    help="load this tuned perf profile instead of the "
                         "auto-discovered per-backend one (invalid/"

@@ -20,6 +20,7 @@ from __future__ import annotations
 from typing import Any, Callable, Dict, Iterable, List, Optional, Sequence
 
 from jepsen_tpu.generator import pure as gen
+from jepsen_tpu.obs import trace as obs_trace
 
 
 class KV:
@@ -225,18 +226,23 @@ class IndependentChecker:
         self.checker = checker
 
     def check(self, test, history, opts=None) -> dict:
+        with obs_trace.span("independent.check", kind="check"):
+            return self._check(test, history, opts)
+
+    def _check(self, test, history, opts) -> dict:
         from jepsen_tpu.history.history import History
 
-        if not isinstance(history, History):
-            history = History(list(history))
-        subhistories: Dict[Any, List] = {}
-        for op in history.ops:
-            v = op.value
-            if not isinstance(v, KV):
-                continue
-            subhistories.setdefault(v.key, []).append(
-                op.with_(value=v.value)
-            )
+        with obs_trace.span("prep.split", kind="prep"):
+            if not isinstance(history, History):
+                history = History(list(history))
+            subhistories: Dict[Any, List] = {}
+            for op in history.ops:
+                v = op.value
+                if not isinstance(v, KV):
+                    continue
+                subhistories.setdefault(v.key, []).append(
+                    op.with_(value=v.value)
+                )
         # Per-key artifacts (independent.clj:266-288 writes each key's
         # results + history under independent/<key>/): mirror that when
         # the test has a run directory.
@@ -273,7 +279,8 @@ class IndependentChecker:
         for k, ops in sorted(
             subhistories.items(), key=lambda kv: str(kv[0])
         ):
-            sub = History(ops)
+            with obs_trace.span("prep.history", kind="prep"):
+                sub = History(ops)
             sub_opts = dict(opts or {})
             key_dir = None
             if run_dir:

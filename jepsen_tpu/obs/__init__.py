@@ -19,8 +19,10 @@ industry-standard artifacts:
 - ``obs.prom``: Prometheus text exposition folding in every ``*_STATS``
   surface plus trace-derived latency histograms and per-tenant /
   per-device labeled gauge families
-- ``obs.xla``: ``xla_trace(dir)`` jax.profiler capture (no-op on
-  meshes without a profiler), unified here from utils/profiling.py
+- ``obs.xla``: ``xla_trace(dir)`` jax.profiler capture with the
+  recorder on, so the spans land on the capture's host plane (raises
+  when the profiler cannot start), unified here from
+  utils/profiling.py
 - ``obs.snapshot``: the ONE consolidated ``engine_snapshot()`` behind
   ``cli._engine_stats``, the daemon's ``/stats``, and the dryrun
   metric line (imported lazily — it pulls the jax-backed checker
@@ -34,6 +36,7 @@ inside a per-device/per-member fan-out loop.
 
 from jepsen_tpu.obs.trace import (  # noqa: F401
     TRACER,
+    current,
     disable,
     enable,
     instant,

@@ -86,8 +86,7 @@ def test_disabled_mode_full_check_allocates_no_rings():
     assert obs_trace.TRACER._rings == {}
     assert obs.trace_stats() == {
         "enabled": False, "events": 0, "spans": 0, "instants": 0,
-        "dropped": 0, "sample_n": 1, "kinds": None, "sampled_out": 0,
-        "by_kind": {},
+        "dropped": 0, "by_kind": {},
     }
 
 
@@ -119,55 +118,132 @@ def test_per_thread_rings_stamp_tid_and_tname():
     assert by_name["from_worker"]["tid"] != by_name["from_main"]["tid"]
 
 
-# -- per-kind enable masks + 1-in-N sampling (round 11) ---------------
+# -- span tree, thread-instance rings, profiler annotations ------------
 
 
-def test_kind_mask_records_only_enabled_kinds():
-    obs.enable(kinds=["dispatch"])
-    obs.instant("keep", kind="dispatch")
-    obs.instant("drop", kind="service")
-    with obs.span("drop_too", kind="launch"):
+def test_nested_spans_carry_id_parent_and_root():
+    obs.enable()
+    with obs.span("outer"):
+        with obs.span("mid"):
+            with obs.span("leaf"):
+                pass
+        with obs.span("sibling"):
+            pass
+    with obs.span("second_root"):
         pass
-    names = [e["name"] for e in obs.spans()]
-    assert names == ["keep"]
+    by = {e["name"]: e for e in obs.spans()}
+    outer = by["outer"]
+    assert outer["parent"] is None and outer["root"] == outer["id"]
+    assert by["mid"]["parent"] == outer["id"]
+    assert by["leaf"]["parent"] == by["mid"]["id"]
+    assert by["sibling"]["parent"] == outer["id"]
+    assert {by[n]["root"] for n in ("mid", "leaf", "sibling")} == {outer["id"]}
+    second = by["second_root"]
+    assert second["parent"] is None and second["root"] == second["id"]
+    ids = [e["id"] for e in by.values()]
+    assert len(set(ids)) == len(ids)
+
+
+def test_explicit_parent_crosses_threads():
+    assert obs.current() is None  # recorder off
+    obs.enable()
+    assert obs.current() is None  # no span open
+    seen = []
+
+    def work(pid):
+        # a fresh thread has no open span of its own
+        seen.append(obs.current())
+        with obs.span("worker", parent=pid):
+            pass
+
+    with obs.span("caller") as caller:
+        with obs.span("inner") as inner:
+            assert obs.current() == inner.id
+            t = threading.Thread(target=work, args=(obs.current(),))
+            t.start()
+            t.join()
+        assert obs.current() == caller.id
+    assert seen == [None]
+    by = {e["name"]: e for e in obs.spans()}
+    assert by["worker"]["parent"] == by["inner"]["id"]
+    assert by["worker"]["root"] == by["caller"]["id"]
+    assert by["worker"]["tid"] != by["caller"]["tid"]
+
+
+def test_short_lived_threads_keep_every_span():
+    """Thread idents are reused as soon as a thread exits; a ring per
+    thread INSTANCE keeps every one of their spans."""
+    obs.enable()
+
+    def work(i):
+        with obs.span("short", i=i):
+            pass
+
+    for i in range(200):
+        t = threading.Thread(target=work, args=(i,))
+        t.start()
+        t.join()
     st = obs.trace_stats()
-    assert st["kinds"] == ["dispatch"]
-    # masked-out kinds vanish SILENTLY (never enabled) — they do not
-    # count as sampled_out
-    assert st["sampled_out"] == 0
+    assert st["spans"] == 200 and st["dropped"] == 0
+    assert sorted(e["args"]["i"] for e in obs.spans()) == list(range(200))
 
 
-def test_sampling_counts_thinned_emissions_in_ring_metadata():
-    obs.enable(sample_n=4)
-    for i in range(100):
-        obs.instant("tick", kind="soak", i=i)
-    st = obs.trace_stats()
-    assert st["sample_n"] == 4
-    assert st["events"] == 25
-    assert st["sampled_out"] == 75
-    assert st["events"] + st["sampled_out"] == 100
-    # a sampled trace is detectable exactly like a trimmed one:
-    # reset() zeroes the thinning counters with the rings
-    obs_trace.reset()
-    assert obs.trace_stats()["sampled_out"] == 0
+def test_reset_forgets_rings_of_exited_threads():
+    obs.enable()
+    ts = [threading.Thread(target=lambda: obs.instant("x")) for _ in range(20)]
+    for t in ts:
+        t.start()
+        t.join()
+    obs.instant("main")
+    assert len(obs_trace.TRACER._rings) == 21
+    obs.reset()
+    # only the live (main) thread's ring survives, emptied
+    assert len(obs_trace.TRACER._rings) == 1
+    assert obs.spans() == []
+    obs.instant("again")
+    assert [e["name"] for e in obs.spans()] == ["again"]
 
 
-def test_sampled_out_span_is_the_noop_singleton():
-    # the thinned path reads no clock and allocates no span object
-    obs.enable(kinds=["launch"], sample_n=2)
-    spans = [obs.span("probe", kind="launch") for _ in range(4)]
-    noops = [s for s in spans if s is obs_trace._NOOP]
-    assert len(noops) == 2
-    assert obs.span("masked", kind="service") is obs_trace._NOOP
+def test_span_lands_on_profiler_host_plane(tmp_path):
+    """With jax imported and the recorder on, a span holds a profiler
+    annotation of its name: under a capture it appears by name on a
+    ``/host:`` plane of the .xplane.pb, on the device trace's clock."""
+    import glob
+    import os
+
+    import jax
+    from jax.profiler import ProfileData
+
+    obs.enable()
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        with obs.span("probe.annotated"):
+            with obs.span("probe.inner"):
+                pass
+    finally:
+        jax.profiler.stop_trace()
+    (path,) = glob.glob(os.path.join(
+        str(tmp_path), "plugins", "profile", "*", "*.xplane.pb"))
+    found = {}
+    for plane in ProfileData.from_file(path).planes:
+        for line in plane.lines:
+            for e in line.events:
+                if e.name.startswith("probe."):
+                    found[e.name] = plane.name
+    assert set(found) == {"probe.annotated", "probe.inner"}
+    assert all(p.startswith("/host:") for p in found.values())
 
 
-def test_plain_enable_resets_to_full_fidelity():
-    obs.enable(kinds=["dispatch"], sample_n=16)
-    obs.enable()  # the historical record-everything mode
-    assert obs_trace.TRACER.kinds is None
-    assert obs_trace.TRACER.sample_n == 1
-    obs.instant("any", kind="whatever")
-    assert len(obs.spans()) == 1
+def test_importing_obs_leaves_jax_unimported():
+    import subprocess
+
+    code = ("import sys; import jepsen_tpu.obs as o; o.enable(); "
+            "s = o.span('a'); s.__enter__(); s.__exit__(); "
+            "assert 'jax' not in sys.modules, 'jax imported'; "
+            "assert o.spans()[0]['name'] == 'a'")
+    p = subprocess.run([sys.executable, "-c", code], capture_output=True,
+                       text=True, timeout=120)
+    assert p.returncode == 0, p.stderr
 
 
 # -- launch-accounting parity (the differential pin) ------------------
@@ -518,12 +594,22 @@ def test_cli_trace_summary_by_process(tmp_path, capsys):
 # -- xla trace unification (obs/xla absorbed utils/profiling) ----------
 
 
-def test_xla_trace_contextmanager_never_raises(tmp_path):
+def test_xla_trace_raises_when_a_capture_is_running(tmp_path):
+    """A profiler that cannot start fails loudly: an operator who asked
+    for a trace never silently gets none. The capture turns the span
+    recorder on for its duration, and back off after."""
     from jepsen_tpu.obs.xla import xla_trace
 
-    with xla_trace(str(tmp_path / "xla")):
-        x = 1 + 1
-    assert x == 2
+    with xla_trace(str(tmp_path / "outer")):
+        assert obs_trace.TRACER.enabled
+        with pytest.raises(RuntimeError):
+            with xla_trace(str(tmp_path / "inner")):
+                pass
+        assert obs_trace.TRACER.enabled
+    assert not obs_trace.TRACER.enabled
+    # the outer capture stopped cleanly: a new one starts
+    with xla_trace(str(tmp_path / "again")):
+        pass
 
 
 def test_utils_profiling_is_gone():
