@@ -1633,9 +1633,11 @@ class DispatchPlane:
 
     def _finish(self, fut: CheckFuture, out: dict) -> None:
         """Deliver a device-side verdict, running the racer crosscheck
-        first (free differential coverage, sequential discipline)."""
+        first (free differential coverage, sequential discipline). It
+        is never deferred: the collecting thread may be one caller
+        resolving other callers' futures."""
         if fut.racer is not None:
-            _race_crosscheck(fut.racer, out["valid?"])
+            _race_crosscheck(fut.racer, out["valid?"], defer=False)
             fut.racer = None
         if fut.checkpoint is not None and "checkpoint" not in out:
             self._checkpoint_finish(fut, out)

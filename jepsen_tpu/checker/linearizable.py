@@ -28,6 +28,9 @@ the reference's failing-op report, checker.clj:146-154).
 
 from __future__ import annotations
 
+import collections
+import contextlib
+import os
 import threading
 import time
 from typing import Any, Optional
@@ -91,8 +94,6 @@ def interpret_off_chip(who: str) -> bool:
     TPU, interpreted only when the CPU was chosen on purpose
     (``JAX_PLATFORMS=cpu``). Any other backend is an error naming
     what JAX found: finding no chip is never a reason to interpret."""
-    import os
-
     import jax
 
     platform = jax.default_backend()
@@ -263,16 +264,25 @@ def _oracle_decide(events: EventStream, model):
 RACE_MAX_OPS = 20_000
 
 
+#: how long a cross-check waits for a racer that lost to the device:
+#: one that lands within it is compared, a slower one goes unchecked.
+RACE_GRACE_S = 0.05
+
+
 class _NativeRacer:
     """Background native-oracle run for the competition race
     (knossos's `competition` role, checker.clj:128-144): the TPU
     kernel and the C++ oracle start together, the first definite
-    verdict wins, and when both land by decision time the verdicts
-    cross-check — production differential coverage for free.
+    verdict wins, and when both land the verdicts cross-check —
+    production differential coverage for free.
 
     The ctypes call releases the GIL, so the oracle genuinely overlaps
     the device round trip; on a busy single-core host callers start
-    the racer AFTER host-side prep so the threads don't contend."""
+    the racer AFTER host-side prep so the threads don't contend. When
+    the device wins for one key of a keyed history (a
+    deferred_crosschecks scope is open), the racer keeps running while
+    the caller preps the next key, and its cross-check settles later
+    (_Crosschecks); the verdict never depends on it."""
 
     def __init__(self, events: EventStream, model):
         import threading
@@ -331,6 +341,9 @@ RACE_STATS = {
     "native_wins": 0,
     "crosschecked": 0,
     "mismatches": 0,
+    # cross-checks settled after their key's check returned
+    # (deferred_crosschecks)
+    "deferred": 0,
 }
 
 _race_stats_lock = threading.Lock()
@@ -399,16 +412,13 @@ def _race_decide(events, bsteps, handle, racer, model):
     return _native_win_verdict(events, racer, model)
 
 
-def _race_crosscheck(racer, tpu_alive: bool) -> None:
-    """TPU won the race: if the oracle lands within a short grace,
-    cross-check the verdicts — free production differential coverage.
-    A mismatch means an engine bug; it is logged loudly and counted
-    (the differential soaks treat any mismatch as a failure)."""
-    _bump_race("tpu_wins")
-    with obs_trace.span("racer.wait", kind="racer"):
-        racer.join(0.05)
+def _settle_crosscheck(racer, tpu_alive: bool) -> bool:
+    """Compare a racer's verdict with the device's, if it has landed
+    one. A mismatch means an engine bug; it is logged loudly and
+    counted (the differential soaks treat any mismatch as a failure).
+    False when the racer is still running, crashed or declined."""
     if not racer.done() or racer.error or racer.result is None:
-        return
+        return False
     _bump_race("crosschecked")
     native_valid = racer.result[0]
     if bool(native_valid) != bool(tpu_alive):
@@ -420,6 +430,109 @@ def _race_crosscheck(racer, tpu_alive: bool) -> None:
             "engine bug; file with the stream's seed/material",
             tpu_alive, native_valid,
         )
+    return True
+
+
+class _Crosschecks:
+    """The racers that lost to the device on one thread, waiting for
+    their cross-check (deferred_crosschecks). At most `cap` wait: a
+    TPU win past the cap first joins the oldest. Every join gives its
+    racer at least RACE_GRACE_S from the moment the caller blocks, so
+    nothing the immediate cross-check would have compared goes
+    unchecked; each wait is a `racer.wait` span."""
+
+    def __init__(self, cap: int):
+        self.cap = cap
+        self.pending: collections.deque = collections.deque()
+
+    def _settle(self, racer, tpu_alive: bool) -> None:
+        if _settle_crosscheck(racer, tpu_alive):
+            _bump_race("deferred")
+
+    def add(self, racer, tpu_alive: bool) -> None:
+        if len(self.pending) >= self.cap:
+            self.settle_finished()
+        while len(self.pending) >= self.cap:
+            oldest, alive = self.pending.popleft()
+            with obs_trace.span("racer.wait", kind="racer"):
+                oldest.join(RACE_GRACE_S)
+            self._settle(oldest, alive)
+        self.pending.append((racer, tpu_alive))
+
+    def settle_finished(self) -> None:
+        """Settle the racers that have already landed; no wait."""
+        running: collections.deque = collections.deque()
+        for racer, alive in self.pending:
+            if racer.done():
+                self._settle(racer, alive)
+            else:
+                running.append((racer, alive))
+        self.pending = running
+
+    def drain(self) -> None:
+        if not self.pending:
+            return
+        deadline = time.perf_counter() + RACE_GRACE_S
+        with obs_trace.span("racer.wait", kind="racer"):
+            for racer, _ in self.pending:
+                racer.join(max(0.0, deadline - time.perf_counter()))
+        while self.pending:
+            self._settle(*self.pending.popleft())
+
+
+_scope = threading.local()
+
+
+def _crosscheck_cap() -> int:
+    """Racers that may wait at once: one per core the caller leaves
+    free, so 0 — settle at once — on a one-core host."""
+    try:
+        cores = len(os.sched_getaffinity(0))
+    except AttributeError:  # pragma: no cover - no affinity API
+        cores = os.cpu_count() or 1
+    return cores - 1
+
+
+@contextlib.contextmanager
+def deferred_crosschecks():
+    """Defer the cross-checks of TPU wins on this thread until the
+    scope closes. For a caller that checks many short histories in a
+    row (the keys of one keyed history): the native racer of a key the
+    device already decided finishes while the next key is prepped,
+    rather than the caller waiting on it. The scope yields its
+    _Crosschecks, whose settle_finished() the caller may call between
+    checks; on exit every pending racer is joined and compared, so the
+    counters are whole once the scope returns. Re-entrant: a nested
+    scope joins the open one, which drains when the outermost exits.
+    Thread-local: other threads keep the immediate cross-check."""
+    scope = getattr(_scope, "crosschecks", None)
+    if scope is not None:
+        yield scope
+        return
+    scope = _scope.crosschecks = _Crosschecks(_crosscheck_cap())
+    try:
+        yield scope
+    finally:
+        _scope.crosschecks = None
+        scope.drain()
+
+
+def _race_crosscheck(racer, tpu_alive: bool, defer: bool = True) -> None:
+    """TPU won the race: cross-check the verdicts if the oracle lands
+    within RACE_GRACE_S — free production differential coverage. The
+    verdict never depends on it. Inside a deferred_crosschecks scope
+    (and with defer) the racer is left running and settles after its
+    key's check has returned; callers whose cross-check must follow
+    the verdict at once (the checkpointed driver, the dispatch plane's
+    shared collecting path) pass defer=False."""
+    _bump_race("tpu_wins")
+    scope = getattr(_scope, "crosschecks", None) if defer else None
+    if scope is not None and scope.cap > 0:
+        scope.add(racer, tpu_alive)
+        return
+    with obs_trace.span("racer.wait", kind="racer"):
+        racer.join(RACE_GRACE_S)
+    _settle_crosscheck(racer, tpu_alive)
 
 
 def check_events_bucketed(
@@ -439,7 +552,11 @@ def check_events_bucketed(
     and take the first verdict (knossos competition, checker.clj:
     128-144). Default: on for streams the native envelope covers and
     small enough that the losing thread's overrun is bounded
-    (RACE_MAX_OPS). Pass False for pure-TPU measurement runs.
+    (RACE_MAX_OPS). Pass False for pure-TPU measurement runs. When the
+    device wins, the racer's verdict is only cross-checked (counted in
+    RACE_STATS), never used: at once within RACE_GRACE_S, or, inside a
+    deferred_crosschecks scope (the keys of a keyed history), settled
+    after this call returns while the next key runs.
 
     interpret: run the bitset kernel in Pallas interpret mode on CPU —
     the tests' seam for exercising the device branch (race logic,
@@ -499,7 +616,7 @@ def check_events_bucketed(
             )
             if not taint:
                 if racer is not None:
-                    _race_crosscheck(racer, alive)
+                    _race_crosscheck(racer, alive, defer=False)
                     racer = None
                 out = {
                     "valid?": alive,

@@ -230,6 +230,7 @@ class IndependentChecker:
             return self._check(test, history, opts)
 
     def _check(self, test, history, opts) -> dict:
+        from jepsen_tpu.checker.linearizable import deferred_crosschecks
         from jepsen_tpu.history.history import History
 
         with obs_trace.span("prep.split", kind="prep"):
@@ -276,33 +277,37 @@ class IndependentChecker:
                 name = f"{name}~{n}"
         results = {}
         any_false = any_unknown = False
-        for k, ops in sorted(
-            subhistories.items(), key=lambda kv: str(kv[0])
-        ):
-            with obs_trace.span("prep.history", kind="prep"):
-                sub = History(ops)
-            sub_opts = dict(opts or {})
-            key_dir = None
-            if run_dir:
-                key_dir = os.path.join(
-                    run_dir, "independent", key_dirname(k)
-                )
-                os.makedirs(key_dir, exist_ok=True)
-                sub_opts["subdirectory"] = key_dir
-            r = self.checker.check(test, sub, sub_opts)
-            results[k] = r
-            if key_dir:
-                write_results_json(
-                    os.path.join(key_dir, "results.json"), r
-                )
-                write_history_jsonl(
-                    os.path.join(key_dir, "history.jsonl"), sub.ops
-                )
-            v = r.get("valid?")
-            if v is False:
-                any_false = True
-            elif v is not True:
-                any_unknown = True
+        # A key the device decided leaves its native racer running
+        # into the next key; every cross-check settles before return.
+        with deferred_crosschecks() as crosschecks:
+            for k, ops in sorted(
+                subhistories.items(), key=lambda kv: str(kv[0])
+            ):
+                crosschecks.settle_finished()
+                with obs_trace.span("prep.history", kind="prep"):
+                    sub = History(ops)
+                sub_opts = dict(opts or {})
+                key_dir = None
+                if run_dir:
+                    key_dir = os.path.join(
+                        run_dir, "independent", key_dirname(k)
+                    )
+                    os.makedirs(key_dir, exist_ok=True)
+                    sub_opts["subdirectory"] = key_dir
+                r = self.checker.check(test, sub, sub_opts)
+                results[k] = r
+                if key_dir:
+                    write_results_json(
+                        os.path.join(key_dir, "results.json"), r
+                    )
+                    write_history_jsonl(
+                        os.path.join(key_dir, "history.jsonl"), sub.ops
+                    )
+                v = r.get("valid?")
+                if v is False:
+                    any_false = True
+                elif v is not True:
+                    any_unknown = True
         # Merge lattice: False dominates unknown dominates True
         # (checker.clj:26-69's merge-valid).
         valid = (

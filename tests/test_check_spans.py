@@ -2,9 +2,9 @@
 through ``independent_checker(LinearizableChecker)`` with the bitset
 kernel in interpret mode (CPU) and the native racer on records one
 ``independent.check`` tree per history — ``prep.split``, then per key
-``prep.history`` and a ``check`` holding the prep, launch-and-sync,
-racer and verdict leaves — and each racer thread's ``racer.native``
-hangs under its key's ``check``."""
+``prep.history`` and a ``check`` holding the prep, launch-and-sync
+and verdict leaves, then the drain's ``racer.wait`` — and each racer
+thread's ``racer.native`` hangs under its key's ``check``."""
 
 import random
 import threading
@@ -12,7 +12,10 @@ import threading
 import pytest
 
 from jepsen_tpu import obs
-from jepsen_tpu.checker.linearizable import LinearizableChecker
+from jepsen_tpu.checker.linearizable import (
+    LinearizableChecker,
+    _crosscheck_cap,
+)
 from jepsen_tpu.checker.wgl_native import available as native_available
 from jepsen_tpu.history.history import History
 from jepsen_tpu.history.ops import invoke_op, ok_op
@@ -111,9 +114,10 @@ def test_keyed_check_emits_split_and_per_key_leaf_spans(traced):
             (k, names)
         r = out["results"][k]
         if r.get("race_winner") != "native":
-            # the device decided: its fetch, then the racer's grace;
-            # a death on the fast kernel re-launches on the exact one
-            assert {"host_sync", "racer.wait"} <= set(names), (k, names)
+            # the device decided: its fetch (the racer's cross-check is
+            # deferred past the key); a death on the fast kernel
+            # re-launches on the exact one
+            assert "host_sync" in names, (k, names)
             launches = 2 if r["valid?"] is False else 1
         else:
             launches = 1
@@ -123,6 +127,33 @@ def test_keyed_check_emits_split_and_per_key_leaf_spans(traced):
                   key=lambda s: s["ts"])
     for a, b in zip(prep, prep[1:]):
         assert a["ts"] + a["dur"] <= b["ts"], (a["name"], b["name"])
+
+
+def test_racer_waits_are_the_drain_and_cap_joins(traced):
+    """A device-decided key does not wait on its racer: the caller
+    blocks on racers at the drain, under ``independent.check`` once the
+    last key is done, or at a cap join under a later key's check."""
+    out, spans, _ = traced
+    (root,) = [s for s in spans if s["name"] == "independent.check"]
+    checks = sorted((s for s in spans if s["name"] == "check"),
+                    key=lambda s: s["ts"])
+    waits = [s for s in spans if s["name"] == "racer.wait"]
+    if _crosscheck_cap() == 0:
+        # one core: every TPU win settles in its own check, as ever
+        assert all(w["parent"] in {c["id"] for c in checks} for w in waits)
+        return
+    drains = [w for w in waits if w["parent"] == root["id"]]
+    # a cap join waits on an earlier key's racer, so never in the first
+    assert all(w["parent"] in {c["id"] for c in checks[1:]}
+               for w in waits if w not in drains)
+    last = out["results"][max(out["results"], key=str)]
+    if last.get("race_winner") != "native":
+        # the last key's racer is pending when its check returns
+        (drain,) = drains
+        end = checks[-1]["ts"] + checks[-1]["dur"]
+        assert drain["ts"] >= end and drain["tid"] == root["tid"]
+    else:
+        assert len(drains) <= 1
 
 
 def test_invalid_key_emits_verdict_harvest(traced):
