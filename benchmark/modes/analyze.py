@@ -8,12 +8,21 @@ in ``independent_checker`` for a multi-key history (the
 
 Set-up builds the pool (``traffic["pool"]`` distinct histories from
 the seed; those listed in ``traffic["invalid"]`` lose one write in one
-key) and checks one valid history and every invalid one, which
-compiles or loads every program the window runs: every history of the
-pool has the same shapes. The window checks the pool's histories one
-after another, in an order drawn from the seed and round again, and
-ends with the check that ends past ``--seconds``; the rate is the
-invoked ops of every history checked over the window's length.
+key) and checks the warm set, which compiles or loads every program
+the window runs: first one valid history (the first in the window's
+order) and every invalid one, then the pool's other histories in the
+window's order for as long as the check before needed a program
+(``run.Ctx.warm`` yields each for the mode to check). Where every
+history compiles to the same programs as the others of its verdict,
+as where every key's window stays in one bucket, it ends one history
+past the first set at most; where each history compiles programs of
+its own, it is the whole pool. The harness stops set-up before a warm
+check that would end past its budget (the run exits 3).
+
+The window checks the pool's histories one after another, in the same
+order drawn from the seed and round again, and ends with the check
+that ends past ``--seconds``; the rate is the invoked ops of every
+history checked over the window's length.
 
 After the window each history's verdict, and each key's, is compared
 with the reference's, and every verdict is audited for where it came
@@ -125,12 +134,9 @@ def run(ctx) -> dict:
             return checker.check(test, History(prog[i], indexed=True))
 
     invalid = set(ctx.traffic["invalid"])
-    warm = [i for i in order if i not in invalid][:1] + sorted(invalid)
-    warm_walls = []
-    for i in warm:
-        t = time.perf_counter()
+    first = [i for i in order if i not in invalid][:1] + sorted(invalid)
+    for i in ctx.warm(first, [i for i in order if i not in first]):
         check(i)
-        warm_walls.append(time.perf_counter() - t)
     ctx.split("warm_up")
 
     records = []
@@ -200,8 +206,8 @@ def run(ctx) -> dict:
             "race": d["race"],
         },
         "detail": {
-            "histories": n_checks, "ops": ops, "order": order, "warm": warm,
-            "warm_walls_s": warm_walls,
+            "histories": n_checks, "ops": ops, "order": order, "warm": ctx.warmed,
+            "warm_walls_s": ctx.warm_walls, "warm_compiles": ctx.warm_compiles,
             "invalid_in_pool": [i for i, w in enumerate(want) if not w["all"]],
             "checked": [i for i, _ in records],
             "check_walls_s": walls,

@@ -17,7 +17,9 @@ per-layer metrics, each read by ``benchmark/metrics/<metric>.py``.
 
 The last stdout line is the result object; the last stderr lines are
 each compared number beside its limit. Off a TPU, or with fewer chips
-than the cell asks for, it exits 2 and prints no result.
+than the cell asks for, it exits 2 and prints no result. A set-up that
+would pass its budget stops: the run exits 3, prints no result, and its
+last stderr line names the budget.
 """
 
 from __future__ import annotations
@@ -33,7 +35,7 @@ import json  # noqa: E402
 import os  # noqa: E402
 import sys  # noqa: E402
 import tempfile  # noqa: E402
-from typing import Dict, List, Optional  # noqa: E402
+from typing import Dict, Iterator, List, Optional  # noqa: E402
 
 BENCH = os.path.dirname(os.path.abspath(__file__))
 ROOT = os.path.dirname(BENCH)
@@ -46,6 +48,15 @@ for _p in (ROOT, BENCH):
 #: in its directory and fails every write when one lacks it, as entries
 #: written without a bound (other tools', on another machine) do.
 CACHE_DIR = os.path.join(ROOT, ".jax_cache", "benchmark")
+#: seconds from process start that set-up may take, the same for every
+#: cell: the 360 s a run may take, less the longest window (51 s) and
+#: ~40 s for the reference, the reduction and exit
+SETUP_BUDGET_S = 270
+#: the same for a cell's first run in a checkout, which compiles and may
+#: take 1,200 s
+FIRST_RUN_SETUP_BUDGET_S = 1110
+#: one empty file per cell that has started a run in this checkout
+STARTED_DIR = os.path.join(ROOT, ".jax_cache", "benchmark-started")
 #: per-run detail files (set-up split, compile log, check walls, trace
 #: summary), one per run
 OUT_DIR = os.path.join(ROOT, "chiprun_out", "bench")
@@ -57,6 +68,10 @@ def log(msg: str) -> None:
 
 class Refused(Exception):
     """The run cannot measure here; exit 2, print no result."""
+
+
+class OverBudget(Exception):
+    """Set-up would pass its budget; exit 3, print no result."""
 
 
 def load_module(path: str, name: str):
@@ -105,7 +120,8 @@ class Ctx:
     """What a mode is given: the cell, its configuration and traffic,
     the run's arguments, and the window helper."""
 
-    def __init__(self, args, cell, cfg, traffic, devices, compiles):
+    def __init__(self, args, cell, cfg, traffic, devices, compiles, start,
+                 budget_s):
         self.args = args
         self.cell = cell
         self.cfg = cfg
@@ -116,12 +132,18 @@ class Ctx:
         self.seconds = float(args.seconds)
         self.trace = bool(args.trace)
         self.setup_split: Dict[str, float] = {}
-        self._mark = T0
+        self.start = start
+        self.budget_s = budget_s
+        self._mark = start
         self.window_s: Optional[float] = None
         self.setup_s: Optional[float] = None
         self.trace_summary: Optional[dict] = None
         self.program_spans: List[dict] = []
         self._perf_at_window: Optional[int] = None
+        self.warmed: list = []
+        self.warm_walls: List[float] = []
+        self.warm_compiles: List[dict] = []
+        self._warmed = (0, 0)  # warm checks done, of at most how many
 
     def memory_peak(self) -> Optional[int]:
         """Peak bytes in use on the fullest chip of the cell."""
@@ -138,11 +160,55 @@ class Ctx:
         self.setup_split[name] = now - self._mark
         self._mark = now
 
+    def budget(self, next_s: float = 0.0) -> None:
+        """Stop set-up (``OverBudget``) where it has passed its budget,
+        or would pass it with ``next_s`` seconds more."""
+        used = time.perf_counter() - self.start
+        if used + next_s > self.budget_s:
+            done, most = self._warmed
+            nxt = f", the next expected to take {next_s:.1f} s" if next_s else ""
+            raise OverBudget(
+                f"set-up stopped at its budget of {self.budget_s} s: {done} "
+                f"of at most {most} warm checks done, {used:.1f} s used{nxt}; "
+                "no window was measured")
+
+    def warm(self, first: list, rest: list = ()) -> Iterator:
+        """Set-up's warm checks: yields each history for the mode to
+        check, in its own frame (called from a frame of the harness, the
+        first check traced its kernels 6-9 s slower on a TPU v5e): each of
+        ``first``, then each of ``rest`` in turn while the check before
+        it needed a program (a compile request, from the persistent
+        cache or not); a history that needed none shows the pool's
+        programs are made. Keeps the histories warmed (``warmed``), each
+        one's wall (``warm_walls``) and what each added to the compile
+        log's counts (``warm_compiles``). Before each, stops set-up where
+        that check, if it took as long as the longest so far, would end
+        past the budget. A first check that alone passes the budget
+        cannot be foreseen, and runs to its end."""
+        items = list(first) + list(rest)
+        for n, i in enumerate(items):
+            if n >= len(first) and self.warm_compiles and not (
+                    self.warm_compiles[-1].get("backend_compiles")
+                    or self.warm_compiles[-1].get("cache_hits")):
+                break
+            self._warmed = (n, len(items))
+            self.budget(max(self.warm_walls, default=0.0))
+            before = dict(self.compiles.counts.get("setup", {}))
+            t = time.perf_counter()
+            yield i
+            self.warm_walls.append(time.perf_counter() - t)
+            after = self.compiles.counts.get("setup", {})
+            self.warm_compiles.append(
+                {k: v - before.get(k, 0) for k, v in after.items()})
+            self.warmed.append(i)
+        self._warmed = (len(self.warmed), len(self.warmed))
+
     @contextlib.contextmanager
     def window(self):
         """The measured window: set-up ends here. Under ``--trace 1``
         the profiler (Python tracer off) and the program's span
         recorder run for exactly this block."""
+        self.budget()
         import jax
 
         from jepsen_tpu.obs import trace as obs_trace
@@ -157,7 +223,7 @@ class Ctx:
             opts.host_tracer_level = 2
             jax.profiler.start_trace(log_dir, profiler_options=opts)
         self.split("to_window")
-        self.setup_s = time.perf_counter() - T0
+        self.setup_s = time.perf_counter() - self.start
         self.compiles.phase = "window"
         t0 = time.perf_counter()
         try:
@@ -206,6 +272,9 @@ def main(argv=None) -> int:
     except Refused as e:
         log(f"REFUSED: {e}")
         return 2
+    except OverBudget as e:
+        log(f"STOPPED: {e}")
+        return 3
 
 
 def resolve(name: str):
@@ -262,16 +331,26 @@ def run(args) -> int:
     jax.config.update("jax_compilation_cache_dir", CACHE_DIR)
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
     jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
-    out = measure(args, spec, cell, cfg, traffic, devices)
+    os.makedirs(STARTED_DIR, exist_ok=True)
+    started = os.path.join(STARTED_DIR, cell["name"])
+    budget_s = SETUP_BUDGET_S if os.path.exists(started) else FIRST_RUN_SETUP_BUDGET_S
+    open(started, "a").close()
+    out = measure(args, spec, cell, cfg, traffic, devices, start=T0,
+                  budget_s=budget_s)
     print(json.dumps(out), flush=True)
     return 0
 
 
-def measure(args, spec, cell, cfg, traffic, devices) -> dict:
+def measure(args, spec, cell, cfg, traffic, devices, start=None,
+            budget_s=None) -> dict:
     """Set-up, window, reference comparison and metrics of one run on
-    ``devices``; returns the result object."""
+    ``devices``; returns the result object. Set-up counts from ``start``
+    (by default, now) and may take ``budget_s`` (by default,
+    ``SETUP_BUDGET_S``)."""
+    start = time.perf_counter() if start is None else start
+    budget_s = SETUP_BUDGET_S if budget_s is None else budget_s
     compiles = CompileLog()
-    ctx = Ctx(args, cell, cfg, traffic, devices, compiles)
+    ctx = Ctx(args, cell, cfg, traffic, devices, compiles, start, budget_s)
     ctx.split("import_and_backend")
     mode = load_module(os.path.join(BENCH, "modes", traffic["mode"] + ".py"),
                        "bench_mode_" + traffic["mode"])
@@ -325,6 +404,7 @@ def measure(args, spec, cell, cfg, traffic, devices) -> dict:
     detail = {
         "workload": cell["name"], "seed": args.seed, "trace": args.trace,
         "seconds": args.seconds, "setup_s": ctx.setup_s,
+        "setup_budget_s": ctx.budget_s,
         "window_s": ctx.window_s, "setup_split": ctx.setup_split,
         "compiles": compiles.counts, "result": out,
         "detail": res.get("detail"), "trace_summary": ctx.trace_summary,
