@@ -29,6 +29,11 @@ jepsen/src/jepsen/core.clj:199-232,338-355):
   past the reference's ~20-processes-per-key guidance
   (linearizable_register.clj:44-53); the multi-word masks are what keep
   such histories on the accelerator.
+
+A keyed history (jepsen_tpu.independent) need not become a History per
+key first: split_keyed groups it by key and pairs each key's ops in one
+pass, and records_to_events — the encode history_to_events runs too —
+turns a key's records into the events of that key's History.
 """
 
 from __future__ import annotations
@@ -45,6 +50,7 @@ import numpy as np
 
 from jepsen_tpu.checker.models import F_CAS, F_READ, Model, model as get_model
 from jepsen_tpu.history.history import History
+from jepsen_tpu.history.ops import FAIL, INFO, INVOKE, OK, Op
 
 EV_INVOKE, EV_RETURN, EV_NOP = 0, 1, 2
 
@@ -686,6 +692,13 @@ def events_to_steps_loop(events: EventStream, W: int) -> ReturnSteps:
     )
 
 
+#: an invocation's state in an encode record: its :ok completion was
+#: seen (the record holds the completion's value), its :fail completion
+#: was (the op never happened), or neither — an :info completion or none,
+#: so the op crashed
+INV_OK, INV_FAILED, INV_CRASHED = 0, 1, 2
+
+
 def history_to_events(
     history: History,
     model: Any = "cas-register",
@@ -710,8 +723,50 @@ def history_to_events(
     free heap would (streaming.py's windowed frontier GC relies on all
     three properties).
     """
+    return records_to_events(
+        _completed_records(history.complete()), model, init_value,
+        max_window=max_window, value_codes=value_codes,
+        min_window=min_window,
+    )
+
+
+def _completed_records(h: History):
+    """The encode records (records_to_events) of a complete()d
+    history's client ops, in history order."""
+    pairs = h.pairs()
+    for op in h.ops:
+        if not op.is_client_op:
+            continue
+        if op.is_invoke:
+            state = (
+                INV_FAILED if op.get("fails")
+                else INV_CRASHED if op.get("crashed")
+                else INV_OK
+            )
+            yield (EV_INVOKE, op.index, op.f, op.value, state)
+        elif op.is_ok:
+            yield (EV_RETURN, op.index, pairs.get(op.index))
+        # :fail completions: the invocation is already dropped by its
+        # state. :info completions: the op stays open forever; nothing.
+
+
+def records_to_events(
+    records,
+    model: Any = "cas-register",
+    init_value: Any = None,
+    max_window: int = MAX_WINDOW,
+    value_codes: Optional[Dict[Any, int]] = None,
+    min_window: int = 0,
+) -> EventStream:
+    """Encode records into an EventStream: the one definition of an
+    event, behind history_to_events and the keyed split (split_keyed).
+
+    records, in history order: (EV_INVOKE, index, f, value, state) per
+    client invocation, value being its :ok completion's where it has
+    one and state an INV_*; (EV_RETURN, index, invocation index or
+    None) per client :ok completion. Arguments as history_to_events.
+    """
     m: Model = get_model(model)
-    h = history.complete()
 
     # Value interning local to this check: None -> NIL, else dense codes.
     # Keyed through intern_key so True/1 and 0/False stay distinct (same
@@ -756,13 +811,10 @@ def history_to_events(
     window = max(int(min_window), 0)
     n_ops = 0
 
-    pairs = h.pairs()
-
-    def encode_fab(op) -> Optional[tuple]:
-        fc = m.f_code(op.f)
+    def encode_fab(f, v, index) -> Optional[tuple]:
+        fc = m.f_code(f)
         if fc < 0:
             return None
-        v = op.value
         # Only cas payloads spread [old, new] across (a, b); any other
         # value — including a 2-element list written to the register —
         # interns whole (same gating as columnar.Encoder.encode_payload).
@@ -773,22 +825,21 @@ def history_to_events(
             if not (isinstance(v, (list, tuple)) and len(v) == 2):
                 raise ValueError(
                     f"cas payload must be a 2-element [old, new], "
-                    f"got {v!r} at history index {op.index}"
+                    f"got {v!r} at history index {index}"
                 )
             return (fc, code(v[0]), code(v[1]))
         return (fc, code(v), 0)
 
-    for op in h.ops:
-        if not op.is_client_op:
-            continue
-        if op.is_invoke:
-            if op.get("fails"):
+    for rec in records:
+        if rec[0] == EV_INVOKE:
+            _, index, f, v, state = rec
+            if state == INV_FAILED:
                 continue  # :fail — the op never happened
-            fab = encode_fab(op)
+            fab = encode_fab(f, v, index)
             if fab is None:
                 continue  # outside the model
             fc, a, b = fab
-            if op.get("crashed") and fc in m.crashed_droppable_fs:
+            if state == INV_CRASHED and fc in m.crashed_droppable_fs:
                 continue  # unconstrained crashed op: no effect
             if free:
                 s = heapq.heappop(free)
@@ -798,9 +849,9 @@ def history_to_events(
             else:
                 raise WindowOverflow(
                     f"more than {max_window} concurrently-open ops "
-                    f"at history index {op.index}"
+                    f"at history index {index}"
                 )
-            open_slot[op.index] = s
+            open_slot[index] = s
             window = max(window, s + 1)
             n_ops += 1
             kind.append(EV_INVOKE)
@@ -808,9 +859,9 @@ def history_to_events(
             fcol.append(fc)
             acol.append(a)
             bcol.append(b)
-            op_index.append(op.index)
-        elif op.is_ok:
-            inv = pairs.get(op.index)
+            op_index.append(index)
+        else:
+            _, index, inv = rec
             if inv is None or inv not in open_slot:
                 continue
             s = open_slot.pop(inv)
@@ -820,9 +871,7 @@ def history_to_events(
             fcol.append(0)
             acol.append(0)
             bcol.append(0)
-            op_index.append(op.index)
-        # :fail completions: invocation already dropped via `fails` mark.
-        # :info completions: op stays open forever; emit nothing.
+            op_index.append(index)
 
     return EventStream(
         kind=np.asarray(kind, np.int32),
@@ -836,3 +885,95 @@ def history_to_events(
         value_codes=dict(codes),
         op_index=np.asarray(op_index, np.int32),
     )
+
+
+#: op extras the encoder reads as flags (complete() sets them): a
+#: recorded op that already carries one is left to its key's History
+_FLAG_EXTRAS = frozenset(("fails", "crashed"))
+
+
+class KeyOps:
+    """One key's share of a keyed history (split_keyed): its ops as
+    recorded, and their encode records (records_to_events), or None
+    where the split declined the key. Iterates as the key's ops, values
+    unwrapped, so a checker given one may take it as any op sequence."""
+
+    __slots__ = ("ops", "records")
+
+    def __init__(self):
+        self.ops: List[Op] = []
+        self.records: Optional[list] = []
+
+    def sub_ops(self) -> List[Op]:
+        """The key's ops with their values unwrapped, as the key's own
+        History takes them (it indexes them by position)."""
+        return [o.with_(value=o.value.value) for o in self.ops]
+
+    def __iter__(self):
+        return iter(self.sub_ops())
+
+
+def split_keyed(ops) -> Dict[Any, KeyOps]:
+    """Split a keyed history (ops valued as jepsen_tpu.independent KV
+    tuples; other ops are skipped) by key in one pass, in the order the
+    keys first appear. Each key's invocations pair with their
+    completions per process as the key's own History.pairs() pairs
+    them, and each record carries the op's index in that History (its
+    position among the key's ops), so the records encode to the events
+    history_to_events gives for the key's History.
+
+    A key is declined (records None) where its ops hold anything the
+    history sentry could flag, or the encoder would have to guess at: a
+    completion with no open invocation on its process, a process's time
+    running backwards, an op of a process that is not an integer (a
+    nemesis), an op of another type, or one already flagged fails or
+    crashed. A declined key is checked through its History as before,
+    sentry and all; the sentry reads a key the split keeps as clean."""
+    from jepsen_tpu.independent import KV
+
+    keys: Dict[Any, KeyOps] = {}
+    open_inv: Dict[tuple, list] = {}  # (key, process) -> open invocation
+    last_t: Dict[tuple, Any] = {}  # (key, process) -> its latest time
+    for o in ops:
+        v = o.value
+        if not isinstance(v, KV):
+            continue
+        k = v.key
+        g = keys.get(k)
+        if g is None:
+            g = keys[k] = KeyOps()
+        i = len(g.ops)
+        g.ops.append(o)
+        recs = g.records
+        if recs is None:
+            continue
+        p = o.process
+        if not isinstance(p, int) or (
+            o.extra and not _FLAG_EXTRAS.isdisjoint(o.extra)
+        ):
+            g.records = None
+            continue
+        kp = (k, p)
+        t = o.time
+        if t is not None and t >= 0:
+            if t < last_t.get(kp, t):
+                g.records = None
+                continue
+            last_t[kp] = t
+        typ = o.type
+        if typ == INVOKE:
+            # a second invocation on an open process leaves the first
+            # unpaired, as pairs() does: it stays crashed
+            rec = open_inv[kp] = [EV_INVOKE, i, o.f, v.value, INV_CRASHED]
+            recs.append(rec)
+            continue
+        rec = open_inv.pop(kp, None)
+        if rec is None or typ not in (OK, FAIL, INFO):
+            g.records = None
+        elif typ == OK:
+            rec[3] = v.value
+            rec[4] = INV_OK
+            recs.append((EV_RETURN, i, rec[1]))
+        elif typ == FAIL:
+            rec[4] = INV_FAILED
+    return keys

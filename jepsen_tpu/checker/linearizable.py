@@ -38,10 +38,13 @@ from typing import Any, Optional
 import numpy as np
 
 from jepsen_tpu.checker.events import (
+    MAX_WINDOW,
     EventStream,
+    KeyOps,
     WindowOverflow,
     events_to_steps,
     history_to_events,
+    records_to_events,
 )
 from jepsen_tpu.checker.wgl_oracle import check_events_fast as oracle_check_fast
 from jepsen_tpu.checker.wgl_jax import check_steps_jax
@@ -1158,19 +1161,47 @@ class LinearizableChecker:
         model, plan) resumes at the last durable frontier instead of
         starting over (the `analyze --resume` engine). Ignored by
         tiers that don't segment (K-ladder, oracle, queue-by-value).
+
+        history may be one key of a keyed history as events.split_keyed
+        gives it (a KeyOps): where the split kept the key's records and
+        this checker encodes_whole, they go straight to the encode.
         """
         with obs_trace.span("check", kind="check"):
             return self._check(test, history, opts, checkpoint)
+
+    @property
+    def encodes_whole(self) -> bool:
+        """True where check() encodes the history to one event stream:
+        every model but the unordered queue on the device, which
+        decomposes by value (check_queue_by_value). Only then does
+        check() take a KeyOps' records."""
+        return not (self.model == "unordered-queue" and self.use_tpu)
 
     def _check(self, test, history, opts, checkpoint) -> dict:
         from jepsen_tpu.history.history import History
 
         t0 = time.perf_counter()
+        if (
+            isinstance(history, KeyOps) and history.records is not None
+            and self.encodes_whole
+        ):
+            # No History, sentry or complete() of the key's own: the
+            # split declines every key the sentry could flag, and a
+            # clean key's report adds nothing.
+            records = history.records
+            return self._check_encoded(
+                test,
+                lambda max_window: records_to_events(
+                    records, model=self.model, init_value=self.init_value,
+                    max_window=max_window,
+                ),
+                opts, checkpoint, None, t0,
+            )
         with obs_trace.span("prep.sentry", kind="prep"):
             if not isinstance(history, History):
                 history = History(history)
             history, hreport = self._sentry(history)
-        if self.model == "unordered-queue" and self.use_tpu:
+        if not self.encodes_whole:
             # Queue histories decompose by value (locality — see
             # split_queue_history_by_value): one batched kernel pass
             # over per-value substreams instead of a joint scan whose
@@ -1185,21 +1216,27 @@ class LinearizableChecker:
                 out["wall_s"] = time.perf_counter() - t0
                 self._render_failure(test, out, opts)
                 return out
+        return self._check_encoded(
+            test,
+            lambda max_window: history_to_events(
+                history, model=self.model, init_value=self.init_value,
+                max_window=max_window,
+            ),
+            opts, checkpoint, hreport, t0,
+        )
+
+    def _check_encoded(self, test, encode, opts, checkpoint, hreport,
+                       t0) -> dict:
+        """Everything of a check from the encode on: encode(max_window)
+        gives the history's EventStream."""
         try:
             with obs_trace.span("prep.encode", kind="prep"):
-                events = history_to_events(
-                    history, model=self.model, init_value=self.init_value
-                )
+                events = encode(MAX_WINDOW)
         except WindowOverflow:
             # Too concurrent for int32 masks: unbounded oracle decides
             # (and flows into the shared tail below — overflow runs get
             # the same failure artifact and fields as every other path).
-            events = history_to_events(
-                history,
-                model=self.model,
-                init_value=self.init_value,
-                max_window=1 << 20,
-            )
+            events = encode(1 << 20)
             out = _oracle_verdict(*_oracle_decide(events, self.model))
         else:
             if self.use_tpu:

@@ -17,7 +17,8 @@ batch instead of a thread pool per key.
 
 from __future__ import annotations
 
-from typing import Any, Callable, Dict, Iterable, List, Optional, Sequence
+import threading
+from typing import Any, Callable, Dict, Iterable, Optional, Sequence
 
 from jepsen_tpu.generator import pure as gen
 from jepsen_tpu.obs import trace as obs_trace
@@ -230,20 +231,28 @@ class IndependentChecker:
             return self._check(test, history, opts)
 
     def _check(self, test, history, opts) -> dict:
-        from jepsen_tpu.checker.linearizable import deferred_crosschecks
+        from jepsen_tpu.checker.events import split_keyed
+        from jepsen_tpu.checker.linearizable import (
+            LinearizableChecker,
+            deferred_crosschecks,
+        )
         from jepsen_tpu.history.history import History
+        from jepsen_tpu.history.ops import op as coerce_op
 
         with obs_trace.span("prep.split", kind="prep"):
-            if not isinstance(history, History):
-                history = History(list(history))
-            subhistories: Dict[Any, List] = {}
-            for op in history.ops:
-                v = op.value
-                if not isinstance(v, KV):
-                    continue
-                subhistories.setdefault(v.key, []).append(
-                    op.with_(value=v.value)
-                )
+            ops = (
+                history.ops if isinstance(history, History)
+                else [coerce_op(o) for o in history]
+            )
+            keys = split_keyed(ops)
+        # A register checker that encodes a key's history whole is
+        # handed the key as split (its check encodes the records); a
+        # key the split declined, and every key of any other checker,
+        # is checked through its own History.
+        one_pass = (
+            isinstance(self.checker, LinearizableChecker)
+            and self.checker.encodes_whole
+        )
         # Per-key artifacts (independent.clj:266-288 writes each key's
         # results + history under independent/<key>/): mirror that when
         # the test has a run directory.
@@ -276,16 +285,13 @@ class IndependentChecker:
                     return name
                 name = f"{name}~{n}"
         results = {}
+        prep = {"one_pass_keys": 0, "declined_keys": 0}
         any_false = any_unknown = False
         # A key the device decided leaves its native racer running
         # into the next key; every cross-check settles before return.
         with deferred_crosschecks() as crosschecks:
-            for k, ops in sorted(
-                subhistories.items(), key=lambda kv: str(kv[0])
-            ):
+            for k, g in sorted(keys.items(), key=lambda kv: str(kv[0])):
                 crosschecks.settle_finished()
-                with obs_trace.span("prep.history", kind="prep"):
-                    sub = History(ops)
                 sub_opts = dict(opts or {})
                 key_dir = None
                 if run_dir:
@@ -294,12 +300,22 @@ class IndependentChecker:
                     )
                     os.makedirs(key_dir, exist_ok=True)
                     sub_opts["subdirectory"] = key_dir
+                if one_pass and g.records is not None:
+                    prep["one_pass_keys"] += 1
+                    sub = g
+                else:
+                    if one_pass:
+                        prep["declined_keys"] += 1
+                    with obs_trace.span("prep.history", kind="prep"):
+                        sub = History(g.sub_ops())
                 r = self.checker.check(test, sub, sub_opts)
                 results[k] = r
                 if key_dir:
                     write_results_json(
                         os.path.join(key_dir, "results.json"), r
                     )
+                    if sub is g:
+                        sub = History(g.sub_ops())
                     write_history_jsonl(
                         os.path.join(key_dir, "history.jsonl"), sub.ops
                     )
@@ -308,6 +324,7 @@ class IndependentChecker:
                     any_false = True
                 elif v is not True:
                     any_unknown = True
+        _count_prep(prep)
         # Merge lattice: False dominates unknown dominates True
         # (checker.clj:26-69's merge-valid).
         valid = (
@@ -315,13 +332,27 @@ class IndependentChecker:
         )
         out = {
             "valid?": valid,
-            "key_count": len(subhistories),
+            "key_count": len(keys),
             "results": results,
+            "prep": prep,
         }
         stats = engine_stats(results.values())
         if stats is not None:
             out["engine_stats"] = stats
         return out
+
+
+#: keys checked from the split's records (one_pass_keys) and keys a
+#: register checker took through their own History because the split
+#: declined them (declined_keys), over every keyed history checked
+PREP_STATS = {"one_pass_keys": 0, "declined_keys": 0}
+_prep_lock = threading.Lock()
+
+
+def _count_prep(prep: dict) -> None:
+    with _prep_lock:
+        for k, n in prep.items():
+            PREP_STATS[k] += n
 
 
 def independent_checker(checker) -> IndependentChecker:

@@ -2,9 +2,11 @@
 through ``independent_checker(LinearizableChecker)`` with the bitset
 kernel in interpret mode (CPU) and the native racer on records one
 ``independent.check`` tree per history — ``prep.split``, then per key
-``prep.history`` and a ``check`` holding the prep, launch-and-sync
-and verdict leaves, then the drain's ``racer.wait`` — and each racer
-thread's ``racer.native`` hangs under its key's ``check``."""
+a ``check`` holding the prep, launch-and-sync and verdict leaves, then
+the drain's ``racer.wait`` — and each racer thread's ``racer.native``
+hangs under its key's ``check``. A key the split declines is checked
+through its own History: ``prep.history`` before its ``check``, and
+``prep.sentry`` inside it."""
 
 import random
 import threading
@@ -29,8 +31,7 @@ pytestmark = [
 ]
 
 #: the leaves every key's check emits on the caller's thread
-KEY_LEAVES = {"prep.sentry", "prep.encode", "prep.steps", "launch",
-              "device_wait"}
+KEY_LEAVES = {"prep.encode", "prep.steps", "launch", "device_wait"}
 #: keys in the history: all valid but the last
 N_KEYS = 3
 
@@ -51,24 +52,30 @@ def _keyed_history():
     return History(ops)
 
 
+def _declined_history():
+    """_keyed_history with an orphan completion in key 0: the split
+    declines that key, and the sentry repairs it."""
+    ops = _keyed_history().ops
+    return History(ops[:3] + [ok_op(99, "read", tuple_(0, 1))] + ops[3:])
+
+
 def _join_racers():
     for t in threading.enumerate():
         if t.name == "wgl-native-race":
             t.join(30.0)
 
 
-@pytest.fixture(scope="module")
-def traced():
-    """(verdict, spans) of one traced keyed check, after an untraced
-    warm-up check compiled its kernels."""
+def _trace(history):
+    """(verdict, spans, trace stats) of one traced keyed check, after
+    an untraced warm-up check compiled its kernels."""
     checker = independent_checker(LinearizableChecker(interpret=True))
-    checker.check({}, _keyed_history())
+    checker.check({}, history)
     _join_racers()
     obs.disable()
     obs_trace.TRACER.clear()
     obs.enable()
     try:
-        out = checker.check({}, _keyed_history())
+        out = checker.check({}, history)
         _join_racers()
         spans = [s for s in obs.spans() if s["ph"] == "X"]
         stats = obs.trace_stats()
@@ -76,6 +83,16 @@ def traced():
         obs.disable()
         obs_trace.TRACER.clear()
     return out, spans, stats
+
+
+@pytest.fixture(scope="module")
+def traced():
+    return _trace(_keyed_history())
+
+
+@pytest.fixture(scope="module")
+def traced_declined():
+    return _trace(_declined_history())
 
 
 def test_racer_spans_hang_under_their_key_check(traced):
@@ -102,7 +119,10 @@ def test_keyed_check_emits_split_and_per_key_leaf_spans(traced):
     assert all(s["root"] == root["id"] for s in spans)
     kids = [s for s in spans if s["parent"] == root["id"]]
     assert [s["name"] for s in kids].count("prep.split") == 1
-    assert [s["name"] for s in kids].count("prep.history") == N_KEYS
+    # every key is split in one pass: no History, no sentry of its own
+    assert out["prep"] == {"one_pass_keys": N_KEYS, "declined_keys": 0}
+    assert "prep.history" not in [s["name"] for s in kids]
+    assert not [s for s in spans if s["name"] == "prep.sentry"]
     checks = [s for s in kids if s["name"] == "check"]
     assert len(checks) == N_KEYS
     by_key = dict(zip(sorted(out["results"], key=str), checks))
@@ -122,11 +142,39 @@ def test_keyed_check_emits_split_and_per_key_leaf_spans(traced):
         else:
             launches = 1
         assert names.count("launch") == launches, (k, names)
-    # host prep runs one step at a time on the caller's thread
-    prep = sorted((s for s in spans if s["name"].startswith("prep.")),
+    # host prep runs one step at a time on the caller's thread, and
+    # every host-prep span carries the prefix its metric reads
+    prep = sorted((s for s in spans if s["kind"] == "prep"),
                   key=lambda s: s["ts"])
+    assert all(s["name"].startswith("prep.") for s in prep)
     for a, b in zip(prep, prep[1:]):
         assert a["ts"] + a["dur"] <= b["ts"], (a["name"], b["name"])
+
+
+def test_declined_key_keeps_its_history_and_sentry_spans(traced_declined):
+    out, spans, _ = traced_declined
+    assert out["prep"] == {"one_pass_keys": N_KEYS - 1, "declined_keys": 1}
+    assert out["results"][0]["history_report"]["detected"] == {
+        "orphan_completion": 1}
+    (root,) = [s for s in spans if s["name"] == "independent.check"]
+    kids = sorted((s for s in spans if s["parent"] == root["id"]),
+                  key=lambda s: s["ts"])
+    names = [s["name"] for s in kids]
+    assert names.count("prep.split") == 1
+    # the declined key (0, first in key order) builds its History
+    # just before its check; the sentry runs inside that check
+    assert names.count("prep.history") == 1
+    hist = names.index("prep.history")
+    assert names[hist + 1] == "check"
+    checks = [s for s in kids if s["name"] == "check"]
+    for i, chk in enumerate(checks):
+        leaves = [s["name"] for s in spans if s["parent"] == chk["id"]
+                  and s["tid"] == chk["tid"]]
+        assert KEY_LEAVES <= set(leaves), (i, leaves)
+        assert leaves.count("prep.sentry") == (1 if chk is kids[hist + 1]
+                                               else 0), (i, leaves)
+    assert all(s["name"].startswith("prep.")
+               for s in spans if s["kind"] == "prep")
 
 
 def test_racer_waits_are_the_drain_and_cap_joins(traced):
